@@ -8,7 +8,7 @@ modelled persist/restore time proportionally. Three encodings:
 * ``zlib`` — lossless DEFLATE. Bit-exact on decode; falls back to ``raw``
   when a payload is incompressible (random-looking fp32 noise can expand).
 * ``int8`` — blockwise symmetric absmax quantisation through the existing
-  Pallas ``quant_blockwise`` kernel (interpret mode off-TPU). ~4x smaller
+  Pallas ``quant_blockwise`` kernel (interpret mode on the CPU). ~4x smaller
   for fp32 leaves, lossy within the kernel's per-block scale tolerance.
   Non-float leaves and **lossless-allowlisted paths** (optimizer-critical
   state) are never quantised — they silently take the ``zlib`` lossless

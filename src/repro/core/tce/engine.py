@@ -100,7 +100,7 @@ def unflatten_like(tree, flat: Dict[str, np.ndarray]):
     for path, leaf in zip(paths, leaves):
         arr = flat[path]
         if hasattr(leaf, "dtype"):
-            arr = arr.astype(leaf.dtype).reshape(leaf.shape)
+            arr = arr.astype(leaf.dtype, copy=False).reshape(leaf.shape)
         new_leaves.append(arr)
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 

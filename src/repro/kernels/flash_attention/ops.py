@@ -1,7 +1,7 @@
 """jit'd public wrapper for the flash-attention kernel.
 
 Accepts the model-layout (B, S, H, D) / (B, T, KH, D) tensors, transposes to
-the kernel layout, and auto-selects interpret mode on non-TPU backends (the
+the kernel layout, and selects interpret mode on the CPU test backend (the
 kernel body then executes in Python for validation)."""
 from __future__ import annotations
 
@@ -11,11 +11,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
+
 from .flash_attention import flash_attention_bhsd
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -25,7 +23,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: int = 128,
                     interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, S, H, D); k, v: (B, T, KH, D) -> (B, S, H, D)."""
-    interpret = _auto_interpret() if interpret is None else interpret
+    interpret = auto_interpret() if interpret is None else interpret
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

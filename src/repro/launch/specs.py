@@ -17,7 +17,7 @@ from repro.models import ModelConfig, blocks
 I32 = jnp.int32
 
 
-def _batch_specs(cfg: ModelConfig, b: int, s: int, with_labels: bool):
+def batch_specs(cfg: ModelConfig, b: int, s: int, with_labels: bool):
     shapes: Dict[str, Any] = {
         "tokens": jax.ShapeDtypeStruct((b, s), I32),
     }
@@ -46,7 +46,7 @@ def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> Tuple[Dict[str, Any], Dic
     """
     b, s = shape.global_batch, shape.seq_len
     if shape.kind in ("train", "prefill"):
-        shapes, axes = _batch_specs(cfg, b, s, with_labels=(shape.kind == "train"))
+        shapes, axes = batch_specs(cfg, b, s, with_labels=(shape.kind == "train"))
         return {"batch": shapes}, {"batch": axes}
     # decode: one new token against a cache of length s
     enc_len = cfg.encdec.enc_len if cfg.encdec else None

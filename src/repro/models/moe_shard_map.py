@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 from repro.parallel.sharding import active
 
 from .config import ModelConfig
@@ -88,7 +87,7 @@ def moe_forward_shard_map(p, x: jax.Array, cfg: ModelConfig
     w_spec = P("model", None, "data" if "data" in axis_names else None)
     wo_spec = P("model", "data" if "data" in axis_names else None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_moe, cfg=cfg,
                           ep=mesh.shape["model"], dp_axes=dp_axes),
         mesh=mesh,

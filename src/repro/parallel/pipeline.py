@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
-
 
 def pipeline(layer_fn: Callable, stage_params, x: jax.Array, *,
              mesh: Mesh, axis: str = "pod", n_micro: int = None):
@@ -94,7 +92,7 @@ def pipeline(layer_fn: Callable, stage_params, x: jax.Array, *,
         return shard[stage]
 
     p_spec = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(p_spec, P(axis)),
-                   out_specs=P(axis), check_vma=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(p_spec, P(axis)),
+                       out_specs=P(axis), check_vma=False)
     return fn(stage_params, x)

@@ -60,7 +60,7 @@ ACT_RULES: Dict[str, AxisRule] = {
 DEFAULT_RULES: Dict[str, AxisRule] = {**PARAM_RULES, **ACT_RULES}
 
 # ---------------------------------------------------------------------------
-# Presets (hillclimb levers; see EXPERIMENTS.md §Perf)
+# Presets (hillclimb levers)
 # ---------------------------------------------------------------------------
 # megatron (default): 2D param sharding — FSDP over data on embed, TP/EP over
 #   model on heads/mlp/vocab/experts; batch over (pod, data).

@@ -37,7 +37,9 @@ from .base import FaultNotice, RankHealth, StepSlice
 
 def _worker_env() -> Dict[str, str]:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # ranks always run on the CPU: a chip belongs to one process, and N rank
+    # workers inheriting an accelerator platform would contend for it
+    env["JAX_PLATFORMS"] = "cpu"
     # make sure the worker can import repro no matter how the parent was
     # launched (pytest, -m, script): prepend this package's src root
     src_root = str(Path(__file__).resolve().parents[2])

@@ -58,7 +58,7 @@ def main() -> int:
     spec = json.loads(args.spec)
 
     proto = _hijack_stdout()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"   # one chip cannot host N ranks
 
     import jax
     import numpy as np
@@ -69,6 +69,7 @@ def main() -> int:
     from repro.core.tce.fastcopy import crc32_stream
     from repro.core.tce.sharding import shard_state
     from repro.data import SyntheticLMData
+    from repro.launch.train import make_batch
     from repro.train import (AdamConfig, TrainConfig, init_train_state,
                              make_train_step)
 
@@ -99,17 +100,6 @@ def main() -> int:
     def fresh_state():
         return init_train_state(cfg, opt_cfg, jax.random.key(seed))
 
-    def make_batch(step: int):
-        b = {k: jax.numpy.asarray(v) for k, v in data.batch_at(step).items()}
-        if cfg.family == "encdec":
-            b["enc_embeds"] = jax.numpy.zeros(
-                (batch, cfg.encdec.enc_len, cfg.d_model), "float32")
-        if cfg.family == "vlm":
-            b["vision_embeds"] = jax.numpy.zeros(
-                (batch, min(cfg.vlm.n_vision_tokens, seq), cfg.d_model),
-                "float32")
-        return b
-
     state = fresh_state()
     step = 0
     # delta bookkeeping: leaf path -> (content crc, step whose rank dir
@@ -130,7 +120,7 @@ def main() -> int:
         t_sent = cmd.get("t_sent")
         wall0 = time.perf_counter()
         while step < upto:
-            state, metrics = step_fn(state, make_batch(step))
+            state, metrics = step_fn(state, make_batch(cfg, data, step))
             step += 1
             losses.append([step, float(metrics["loss"])])
         wall = (time.time() - t_sent if t_sent is not None

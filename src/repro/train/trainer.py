@@ -9,7 +9,7 @@ pods exchange **int8 row-quantised** gradients via ``all_gather`` instead of
 letting XLA all-reduce bf16 tensors across the (slow, inter-pod) axis. A
 persistent error-feedback buffer would be carried by the optimizer state; we
 use plain absmax quantisation per step (error feedback is unnecessary at int8
-for Adam due to the moment smoothing — noted in EXPERIMENTS.md).
+for Adam due to the moment smoothing).
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models import ModelConfig
-from repro.parallel.compat import shard_map
 from repro.models.model import loss_fn
 
 from .optimizer import AdamConfig, adam_update
@@ -48,10 +47,6 @@ def _quant_leaf(g: jax.Array):
 
 def _cross_pod_mean_int8(grads, axis: str = "pod"):
     """all_gather int8 grads over `axis`, dequantise, mean."""
-    # jax.lax.axis_size only exists on newer jax; psum(1) is the portable way
-    n = jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis)
-
     def one(g):
         g32 = g.astype(jnp.float32)
         q, s = _quant_leaf(g32)
@@ -147,9 +142,9 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamConfig,
             metrics = {k: jax.lax.pmean(v, "pod") for k, v in metrics.items()}
             return new_state, metrics
 
-        fn = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, axis_names={"pod"},
-                       check_vma=False)
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, axis_names={"pod"},
+                           check_vma=False)
         return fn(state, batch)
 
     return stepped

@@ -1,10 +1,5 @@
 """Cross-pod int8 gradient compression: numerical correctness on a real
 multi-device pod axis (full-manual shard_map; subprocess forces 2 devices).
-
-The full-model partial-manual lowering is blocked by an XLA SPMD CHECK
-failure in this jax/XLA version (pre-Shardy) — see EXPERIMENTS.md §Perf; the
-collective-byte saving (int8 all-gather vs bf16 all-reduce = 4x on the pod
-axis) is reported analytically there.
 """
 import subprocess
 import sys
@@ -14,18 +9,17 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
-    from repro.parallel.compat import shard_map
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.train.trainer import _cross_pod_mean_int8
 
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = jax.make_mesh((2,), ("pod",), axis_types=(AxisType.Auto,))
     g_local = jax.random.normal(jax.random.key(0), (2, 64, 128))  # per-pod grads
 
     def f(g):
         return _cross_pod_mean_int8({"w": g}, axis="pod")["w"]
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("pod"),
-                            out_specs=P("pod"), check_vma=False))(g_local)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                                out_specs=P("pod"), check_vma=False))(g_local)
     # both pods must hold the same mean, within int8 quantisation error
     want = jnp.mean(g_local, axis=0)
     got0, got1 = np.asarray(out[0]), np.asarray(out[1])

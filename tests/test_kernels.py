@@ -128,10 +128,26 @@ def test_quant_2d_vs_oracle(n, d, block, rt):
     np.testing.assert_allclose(np.asarray(xd), np.asarray(xr), rtol=1e-6)
 
 
-@pytest.mark.parametrize("shape", [(33,), (7, 129), (4, 4, 100), (1000,)])
+@pytest.mark.parametrize("shape", [(33,), (7, 129), (4, 4, 100), (1000,),
+                                   (300 * 256 - 5,)])
 def test_quant_roundtrip_error_bound(shape):
     x = jax.random.normal(jax.random.fold_in(KEY, sum(shape)), shape) * 2
     q, s = quantize_blockwise(x, block=256)
     xd = dequantize_blockwise(q, s, tuple(shape), block=256)
     amax = float(jnp.max(jnp.abs(x)))
     assert float(jnp.max(jnp.abs(xd - x))) <= amax / 127 * 0.51 + 1e-6
+
+
+# --------------------------------------------------------------------------- #
+# backend selection
+# --------------------------------------------------------------------------- #
+def test_auto_interpret_only_on_cpu(monkeypatch):
+    """Interpret mode is the CPU test backend's; any backend that is neither
+    CPU nor TPU raises instead of silently interpreting."""
+    from repro import kernels
+    assert kernels.auto_interpret() is True
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    assert kernels.auto_interpret() is False
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        kernels.auto_interpret()

@@ -16,7 +16,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models.config import ModelConfig, MoEConfig
     from repro.parallel import sharding as shd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     base = ModelConfig(name="t", d_model=32, vocab_size=64)
     key = jax.random.key(0)
     b, s, d = 4, 64, 32

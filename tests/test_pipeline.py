@@ -10,7 +10,8 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.parallel.pipeline import pipeline
 
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = jax.make_mesh((2,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     n_stages, layers_per_stage, d, b = 2, 3, 16, 8
     key = jax.random.key(0)
     W = jax.random.normal(key, (n_stages, layers_per_stage, d, d)) * 0.3

@@ -13,7 +13,8 @@ from repro.parallel import sharding as shd
 def mesh():
     # single device, but axis sizes 1x1 exercise the code paths; divisibility
     # logic is tested against a fake mesh-shape dict below
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 LOGICAL = list(shd.DEFAULT_RULES.keys()) + [None, "unknown_axis"]

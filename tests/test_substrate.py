@@ -49,6 +49,14 @@ def test_process_substrate_class_has_protocol_surface():
         assert callable(getattr(ProcessSubstrate, name)), name
 
 
+def test_process_ranks_always_run_on_cpu(monkeypatch):
+    # an accelerator platform inherited from the parent must not reach the
+    # rank workers: N ranks cannot share one chip
+    from repro.substrate.process import _worker_env
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert _worker_env()["JAX_PLATFORMS"] == "cpu"
+
+
 def test_build_substrate_rejects_unknown_mode():
     with pytest.raises(ValueError, match="unknown substrate mode"):
         build_substrate("quantum")
