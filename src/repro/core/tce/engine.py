@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.recovery.tiers import (TIER_DEVICE, TIER_DRAM, TIER_NAS,
                                   TIER_PEER, TierTable)
 from repro.sim.clock import SimClock
@@ -259,23 +260,30 @@ class TCEngine:
              wait: bool = False) -> SaveHandle:
         """Checkpoint `state` (pytree or flat dict). Blocks only for the
         in-memory cache write; persistence + backup happen asynchronously."""
-        flat = state if isinstance(state, dict) and all(
-            isinstance(v, np.ndarray) for v in state.values()) \
-            else flatten_pytree(state)
+        if isinstance(state, dict) and all(
+                isinstance(v, np.ndarray) for v in state.values()):
+            flat = state
+        else:
+            with obs.span("transom.save.d2h"):
+                flat = flatten_pytree(state)
+            obs.count("tce.save.d2h_bytes",
+                      sum(a.nbytes for a in flat.values()))
         handle = SaveHandle(step, self)
         if self.cfg.async_persist and self.cfg.pipeline_durability:
             # bounded-staleness pipeline: previous checkpoints become durable
             # before this one enters the cache (no-op in steady state)
-            self.reconciler.quiesce(self.cfg.durability_timeout_s)
+            with obs.span("transom.save.wait"):
+                self.reconciler.quiesce(self.cfg.durability_timeout_s)
         meter0 = METER.read()
         t0 = time.perf_counter()
-        per_node = shard_state(flat, self.cfg.n_nodes)
 
         def _put(rank: int) -> PutStats:
             return self.caches[rank].put(step, per_node[rank],
                                          n_threads=self.cfg.copy_threads)
 
-        puts = self._map(_put, range(self.cfg.n_nodes))
+        with obs.span("transom.save.cache_write"):
+            per_node = shard_state(flat, self.cfg.n_nodes)
+            puts = self._map(_put, range(self.cfg.n_nodes))
         handle.cache_wall_s = time.perf_counter() - t0
         handle.nbytes = sum(p.nbytes for p in puts)
         handle.bytes_staged = sum(p.bytes_staged for p in puts)
@@ -354,6 +362,12 @@ class TCEngine:
         engine with M != N nodes, and the caller re-shards by saving through
         the new engine (elastic shrink/grow).
         """
+        with obs.span("transom.restore"):
+            return self._restore(step, consumers_per_node, plan, prefetch)
+
+    def _restore(self, step: Optional[int], consumers_per_node: int, plan,
+                 prefetch: Optional[PrefetchHandle]
+                 ) -> Tuple[int, Dict[str, np.ndarray]]:
         allowed = None
         if plan is not None:
             allowed = frozenset(getattr(plan, "tiers", plan))
@@ -372,9 +386,8 @@ class TCEngine:
             last_err: Optional[Exception] = None
             for cand in sorted(cached, reverse=True):
                 try:
-                    return self.restore(step=cand,
-                                        consumers_per_node=consumers_per_node,
-                                        plan=plan, prefetch=prefetch)
+                    return self._restore(cand, consumers_per_node, plan,
+                                         prefetch)
                 except FileNotFoundError as e:
                     last_err = e
             raise last_err
@@ -474,7 +487,8 @@ class TCEngine:
                          if src == "cache" and shards]
             if mem_bytes:
                 self.clock.advance(max(mem_bytes) / self.cfg.mem_bw)
-        state = unshard_state(per_node)
+        with obs.span("transom.restore.unshard"):
+            state = unshard_state(per_node)
         with self._lock:
             self.stats["restores"] += 1
             self.stats["restore_sources"] = sources

@@ -29,6 +29,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.sim.clock import SimClock
 
 from .cache import CacheServer
@@ -74,13 +75,11 @@ class Reconciler:
         # rank -> {path: (home_step, digest)} of the last persisted entry;
         # home_step is where the leaf's file actually lives (path-compressed)
         self._persisted_digests: Dict[int, Dict[str, Tuple[int, int]]] = {}
-        self.durable_at: Dict[int, float] = {}   # step -> modelled seconds
         self.errors: List[str] = []
         self.passes = 0
         self.stats = {"delta_leaves_skipped": 0, "delta_leaves_written": 0,
                       "backup_leaves_sent": 0, "backup_leaves_reused": 0,
-                      "backup_bytes_wire": 0, "cpu_bytes_charged": 0,
-                      "cpu_s_charged": 0.0}
+                      "backup_bytes_wire": 0, "cpu_bytes_charged": 0}
 
     # ------------------------------------------------------------------ #
     def start(self) -> None:
@@ -157,7 +156,6 @@ class Reconciler:
         training stall path by construction (the reconciler is async)."""
         if self.cpu_s_per_byte > 0 and nbytes > 0:
             self.stats["cpu_bytes_charged"] += int(nbytes)
-            self.stats["cpu_s_charged"] += nbytes * self.cpu_s_per_byte
             self.clock.advance(nbytes * self.cpu_s_per_byte)
 
     def _digest_map(self, cache: CacheServer, step: int,
@@ -170,9 +168,12 @@ class Reconciler:
         existing = cache.digests(step)
         if existing and all(d is not None for d, _n, _s in existing.values()):
             return {p: d for p, (d, _n, _s) in existing.items()}
-        dig = {p: crc32_stream(d) for p, (sp, d) in shards.items()}
+        with obs.span("transom.persist.digest"):
+            dig = {p: crc32_stream(d) for p, (sp, d) in shards.items()}
+        nbytes = sum(d.nbytes for _, d in shards.values())
+        obs.count("tce.persist.crc_bytes", nbytes)
         cache.set_digests(step, dig)
-        self._charge_cpu(sum(d.nbytes for _, d in shards.values()))
+        self._charge_cpu(nbytes)
         return dig
 
     def _persist(self, cache: CacheServer, step: int, shards: NodeShards,
@@ -287,8 +288,19 @@ class Reconciler:
                              owner_rank=cache.rank)
         cache.mark(step, backed_up=True)
 
+    def _view(self, cache: CacheServer, step: int
+              ) -> Tuple[Optional[NodeShards], Optional[Dict[str, int]]]:
+        """One zero-copy view of an entry and its digests: they feed both
+        the persist and the backup."""
+        shards = cache.get(step)
+        if shards is None or self.legacy:
+            return shards, None
+        return shards, self._digest_map(cache, step, shards)
+
     def reconcile_once(self) -> None:
         self.passes += 1
+        cpu0 = time.thread_time()
+        worked = False
         n = len(self.caches)
         persisted_steps: Dict[int, int] = {}
         for cache in self.caches:
@@ -301,18 +313,20 @@ class Reconciler:
                 want_backup = (self.backup and self.fabric is not None
                                and n > 1 and not ent.backed_up)
                 shards: Optional[NodeShards] = None
-                digmap: Optional[Dict[str, int]] = None
-                if not ent.persisted or want_backup:
-                    # one zero-copy view (and one digest pass) feeds both the
-                    # persist and the backup
-                    shards = cache.get(step)
-                    if shards is not None and not self.legacy:
-                        digmap = self._digest_map(cache, step, shards)
-                if not ent.persisted and shards is not None:
-                    try:
-                        self._persist(cache, step, shards, digmap)
-                    except Exception as e:
-                        self.errors.append(f"persist r{cache.rank} s{step}: {e!r}")
+                if not ent.persisted:
+                    worked = True
+                    with obs.span("transom.persist", step=step,
+                                  rank=cache.rank):
+                        shards, digmap = self._view(cache, step)
+                        if shards is not None:
+                            try:
+                                self._persist(cache, step, shards, digmap)
+                            except Exception as e:
+                                self.errors.append(
+                                    f"persist r{cache.rank} s{step}: {e!r}")
+                elif want_backup:
+                    worked = True
+                    shards, digmap = self._view(cache, step)
                 if want_backup and shards is not None:
                     try:
                         if self.legacy:
@@ -328,12 +342,13 @@ class Reconciler:
         with self._lock:
             for step, cnt in sorted(persisted_steps.items()):
                 if cnt >= n and step not in self._committed:
-                    self.store.commit(step, n,
-                                      delta_base=self._last_committed
-                                      if self.delta else None)
+                    worked = True
+                    with obs.span("transom.persist.commit", step=step):
+                        self.store.commit(step, n,
+                                          delta_base=self._last_committed
+                                          if self.delta else None)
                     self._committed.add(step)
                     self._last_committed = step
-                    self.durable_at[step] = self.clock.seconds
         # tier-aware aging: a TieredStore demotes steps over a leg's
         # capacity budget one rung down the hierarchy (idempotent no-op on
         # plain stores and under-budget legs)
@@ -343,3 +358,5 @@ class Reconciler:
                 demote()
             except Exception as e:
                 self.errors.append(f"demote: {e!r}")
+        if worked:
+            obs.count("tce.reconciler.cpu_s", time.thread_time() - cpu0)

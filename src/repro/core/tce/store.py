@@ -45,6 +45,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.sim.clock import SimClock  # noqa: F401  (canonical clock; re-exported)
 
 from .codec import decode_shard, encode_shard, is_lossless_path
@@ -213,14 +214,19 @@ class DiskStore:
     def _manifest(self, step: int) -> Path:
         return self._step_dir(step) / "manifest.json"
 
-    def _crc(self, data) -> int:
-        if self.legacy_crc:
-            import zlib
-            buf = (np.ascontiguousarray(data).tobytes()
-                   if isinstance(data, np.ndarray) else bytes(data))
-            METER.add(len(buf))              # the copy tobytes() materialises
-            return zlib.crc32(buf) & 0xFFFFFFFF
-        return crc32_stream(data)
+    def _crc(self, data, counter: str) -> int:
+        """crc32 of ``data``, its bytes added to the counter ``counter``."""
+        with obs.span("transom.store.crc"):
+            if self.legacy_crc:
+                import zlib
+                buf = (np.ascontiguousarray(data).tobytes()
+                       if isinstance(data, np.ndarray) else bytes(data))
+                METER.add(len(buf))          # the copy tobytes() materialises
+                crc = zlib.crc32(buf) & 0xFFFFFFFF
+            else:
+                crc = crc32_stream(data)
+        obs.count(counter, memoryview(data).nbytes)
+        return crc
 
     # -- write ---------------------------------------------------------- #
     def write_rank(self, step: int, rank: int, shards: NodeShards, *,
@@ -258,16 +264,19 @@ class DiskStore:
                 lossless=is_lossless_path(path, lossless_paths))
             fname = f"shard_{i:05d}.bin"
             tmp = d / (fname + ".tmp")
-            with open(tmp, "wb") as f:
-                f.write(memoryview(payload))
-                f.flush()
-                os.fsync(f.fileno())
-            os.replace(tmp, d / fname)   # atomic
+            with obs.span("transom.store.write"):
+                with open(tmp, "wb") as f:
+                    f.write(memoryview(payload))
+                    f.flush()
+                    os.fsync(f.fileno())
+                os.replace(tmp, d / fname)   # atomic
+            obs.count("tce.persist.bytes", payload.nbytes)
             stored_total += payload.nbytes
             digest = (digests[path] if digests and path in digests
-                      else self._crc(data))
+                      else self._crc(data, "tce.persist.crc_bytes"))
             ent.update({"file": fname, "enc": enc, "meta": meta,
-                        "crc32": int(self._crc(payload)),
+                        "crc32": int(self._crc(payload,
+                                               "tce.persist.crc_bytes")),
                         "digest": int(digest),
                         "nbytes_stored": int(payload.nbytes)})
             index.append(ent)
@@ -349,9 +358,12 @@ class DiskStore:
                 if hops > 64:
                     raise IOError(f"delta ref cycle for {spec.path}")
             fpath = self._rank_dir(home, rank) / resolved["file"]
-            payload = np.fromfile(fpath, np.uint8)
+            with obs.span("transom.store.read"):
+                payload = np.fromfile(fpath, np.uint8)
+            obs.count("tce.restore.read_bytes", payload.nbytes)
             stored_read += payload.nbytes
-            if verify and int(self._crc(payload)) != resolved["crc32"]:
+            if verify and int(self._crc(payload, "tce.restore.crc_bytes")) \
+                    != resolved["crc32"]:
                 raise IOError(f"checksum mismatch for {spec.path} in rank {rank}")
             data = decode_shard(resolved.get("enc", "raw"), payload,
                                 ent["dtype"], ent["shape"],

@@ -222,11 +222,14 @@ def tree_nbytes(tree) -> int:
 def restore_state(tce, cfg, opt_cfg):
     """Freshest checkpoint -> (step, TrainState of host arrays). Raises
     FileNotFoundError when there is none."""
+    from repro import obs
     from repro.core.tce.engine import unflatten_like
     from repro.train import train_state_shapes
 
     ck_step, flat = tce.restore()
-    return int(ck_step), unflatten_like(train_state_shapes(cfg, opt_cfg), flat)
+    shapes = train_state_shapes(cfg, opt_cfg)
+    with obs.span("transom.restore.unflatten"):
+        return int(ck_step), unflatten_like(shapes, flat)
 
 
 def train_span(plan: StepPlan, state, data, cfg, start: int, stop: int, *,
@@ -236,12 +239,15 @@ def train_span(plan: StepPlan, state, data, cfg, start: int, stop: int, *,
     ``ckpt_every``-th step. Returns (state, [(step, loss, step_s)])."""
     import jax
 
+    from repro import obs
+
     records = []
     for step in range(start, stop):
         batch = make_batch(cfg, data, step)
         t0 = time.perf_counter()
-        state, metrics = plan.step(state, batch)
-        jax.block_until_ready((state, metrics))
+        with obs.step_span(step):
+            state, metrics = plan.step(state, batch)
+            jax.block_until_ready((state, metrics))
         dt = time.perf_counter() - t0
         loss = float(metrics["loss"])
         records.append((step + 1, loss, dt))

@@ -62,7 +62,10 @@ def _cross_pod_mean_int8(grads, axis: str = "pod"):
 # --------------------------------------------------------------------------- #
 def _grads_and_metrics(params, cfg: ModelConfig, batch, tcfg: TrainConfig):
     def lf(p, b):
-        return loss_fn(p, cfg, b, attn_impl=tcfg.attn_impl)
+        # scope paths "loss/..." for the forward, "transpose(jvp(loss))/..."
+        # for its gradient
+        with jax.named_scope("loss"):
+            return loss_fn(p, cfg, b, attn_impl=tcfg.attn_impl)
 
     if tcfg.grad_accum <= 1:
         (loss, metrics), grads = jax.value_and_grad(lf, has_aux=True)(params, batch)
@@ -101,8 +104,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamConfig,
             grads = _cross_pod_mean_int8(grads)
         rng = jax.random.wrap_key_data(state.rng)
         step_rng = jax.random.fold_in(rng, state.step)
-        new_params, new_opt, opt_m = adam_update(
-            state.params, grads, state.opt, state.step, opt_cfg, rng=step_rng)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_m = adam_update(
+                state.params, grads, state.opt, state.step, opt_cfg,
+                rng=step_rng)
         metrics = {**metrics, **opt_m}
         new_state = TrainState(step=state.step + 1, rng=state.rng,
                                params=new_params, opt=new_opt)
