@@ -7,10 +7,13 @@ backed up (the reconciler drives those flags to the desired state).
 
 Datapath contract (zero-copy staging):
 
-* ``put`` moves each leaf's bytes exactly **once** — a direct chunked
-  multi-threaded copy straight into a fresh arena slab. Nothing else happens
-  on the training-stall path: no hashing, no comparing (change detection is
-  the *async* reconciler's job, over zero-copy views of these slabs).
+* ``put`` moves each leaf's bytes at most **once** — a direct chunked
+  multi-threaded copy straight into a fresh arena slab — and not at all for
+  the paths in ``adopt``: a contiguous shard the caller hands over (the
+  engine's own device-to-host buffer) is registered as a slab by reference.
+  Nothing else happens on the training-stall path: no hashing, no comparing
+  (change detection is the *async* reconciler's job, over zero-copy views
+  of these slabs).
 * ``get`` returns **read-only views** into the arena — no copy. Consumers
   that need to mutate (none on the hot path) must copy explicitly. Slabs
   are immutable once staged, so a leaf's content digest, computed once by
@@ -24,13 +27,13 @@ Datapath contract (zero-copy staging):
   lossy-decoded (int8 codec).
 
 ``legacy=True`` restores the pre-datapath behaviour (bounce-buffer staging,
-copying ``get``) for A/B benchmarking.
+copying ``get``, no adoption) for A/B benchmarking.
 """
 from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import AbstractSet, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -69,7 +72,7 @@ class CacheEntry:
 @dataclass(frozen=True)
 class PutStats:
     nbytes: int          # logical bytes in the entry
-    bytes_staged: int    # logical bytes that had to reach the arena (copied once)
+    bytes_staged: int    # bytes copied into the arena (adopted bytes are not)
     reused_leaves: int   # leaves shared with the previous entry (no copy)
 
 
@@ -95,34 +98,49 @@ class CacheServer:
     def _stage(self, data: np.ndarray, n_threads: int) -> Tuple[int, int]:
         """Copy one leaf's bytes into a fresh slab. Returns (sid, staged)."""
         flat = data.view(np.uint8).reshape(-1)
-        sid = self._alloc_with_eviction(flat.nbytes)
+        sid = self._with_eviction(lambda: self.arena.alloc(flat.nbytes))
         chunked_copy(self.arena.view(sid, flat.nbytes), flat,
                      n_threads=n_threads, mode=self.copy_mode)
         return sid, flat.nbytes
 
+    def _adopt(self, data: np.ndarray) -> Tuple[int, int]:
+        """Register one contiguous leaf's own buffer as a slab. Returns
+        (sid, staged=0)."""
+        flat = data.view(np.uint8).reshape(-1)
+        return self._with_eviction(lambda: self.arena.adopt(flat)), 0
+
     def put(self, step: int, shards: NodeShards, *, is_backup: bool = False,
             owner_rank: Optional[int] = None, n_threads: int = 2,
-            digests: Optional[Dict[str, int]] = None) -> PutStats:
-        """Stage a full shard map: one direct copy per leaf, nothing else.
+            digests: Optional[Dict[str, int]] = None,
+            adopt: AbstractSet[str] = frozenset()) -> PutStats:
+        """Stage a full shard map: at most one direct copy per leaf, nothing
+        else.
         ``digests`` passes content digests through (ring-backup receives use
         the *source* digests so cross-cache delta comparisons stay consistent
         for lossy-decoded payloads; own saves leave them for the async
-        reconciler to fill via :meth:`set_digests`)."""
+        reconciler to fill via :meth:`set_digests`). The shards of the
+        paths in ``adopt`` become slabs by reference where they are
+        C-contiguous: the caller hands their buffers over and writes to
+        them no more. A non-contiguous one is copied like any other."""
         owner = self.rank if owner_rank is None else owner_rank
         stored: Dict[str, StoredShard] = {}
         nbytes = staged = 0
         with self._lock:
             try:
                 for path, (spec, data) in shards.items():
+                    take = (path in adopt and not self.legacy
+                            and data.flags.c_contiguous)
                     contig = np.ascontiguousarray(data)
                     if contig is not data and contig.base is not data:
                         METER.add(contig.nbytes)     # forced contiguity copy
                     data = contig
                     nbytes += data.nbytes
                     digest = digests.get(path) if digests else None
-                    sid, n = self._stage(data, n_threads)
+                    sid, n = self._adopt(data) if take \
+                        else self._stage(data, n_threads)
                     staged += n
-                    stored[path] = StoredShard(spec, sid, n, str(data.dtype),
+                    stored[path] = StoredShard(spec, sid, data.nbytes,
+                                               str(data.dtype),
                                                tuple(data.shape), digest)
             except ArenaError:
                 for ss in stored.values():   # no leaked slabs on failure
@@ -263,10 +281,11 @@ class CacheServer:
             self.arena.clear()
 
     # -- eviction -------------------------------------------------------- #
-    def _alloc_with_eviction(self, nbytes: int) -> int:
+    def _with_eviction(self, register: Callable[[], int]) -> int:
+        """``register`` a slab, evicting the oldest entries until it fits."""
         while True:
             try:
-                return self.arena.alloc(nbytes)
+                return register()
             except ArenaError:
                 if not self._evict_oldest():
                     raise

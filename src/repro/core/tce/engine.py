@@ -2,10 +2,14 @@
 
 Save path (paper §IV-C):
   1. snapshot train-state leaves to host memory into per-node cache servers
-     -> training resumes. Zero-copy staging: shard views are copied ONCE,
-     chunked + multi-threaded, straight into pre-allocated arena slabs, and
-     all node caches are written in parallel on a thread pool (the wall
-     clock now matches the "nodes write in parallel" model that
+     -> training resumes. Zero-copy staging: a leaf pulled off an
+     accelerator arrives in a fresh host buffer from its device-to-host
+     transfer, and the cache adopts that buffer's shard views as its slabs
+     — no further copy. Any other leaf (the caller's numpy arrays, or a
+     CPU-backend jax.Array, whose host array is a view of the device
+     buffer) is copied ONCE, chunked + multi-threaded, straight into fresh
+     arena slabs. All node caches are written in parallel on a thread pool
+     (the wall clock now matches the "nodes write in parallel" model that
      ``modeled_cache_s`` always claimed). Nothing else runs on the stall
      path — no checksums, no hashing, no bounce buffers.
   2. asynchronously: reconciler digests the staged slabs (streaming crc32
@@ -78,6 +82,26 @@ def flatten_pytree(tree) -> Dict[str, np.ndarray]:
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     paths = _paths_for(tree, treedef)
     return {path: np.asarray(leaf) for path, leaf in zip(paths, leaves)}
+
+
+def _platform(arr) -> str:
+    """The platform of the devices that hold a ``jax.Array``."""
+    return next(iter(arr.sharding.device_set)).platform
+
+
+def _fresh_host_copies(tree) -> frozenset:
+    """Paths of the leaves whose ``np.asarray`` in :func:`flatten_pytree` is
+    a new host buffer that nobody else writes to: ``jax.Array`` leaves off
+    the CPU platform, which come back through a device-to-host transfer
+    into a buffer JAX marks read-only. On the CPU backend ``np.asarray`` is
+    a view of the device buffer (holding it stops a donating step from
+    donating), and any other leaf belongs to the caller."""
+    import jax
+
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    return frozenset(
+        path for path, leaf in zip(_paths_for(tree, treedef), leaves)
+        if isinstance(leaf, jax.Array) and _platform(leaf) != "cpu")
 
 
 def _key_str(k) -> str:
@@ -185,7 +209,7 @@ class SaveHandle:
         self.cache_wall_s: float = 0.0       # real time to reach cache (blocking)
         self.modeled_cache_s: float = 0.0    # staged bytes / B_mem (paper's metric)
         self.nbytes: int = 0                 # logical checkpoint bytes
-        self.bytes_staged: int = 0           # bytes that had to reach the arena
+        self.bytes_staged: int = 0           # bytes copied into the arena
         # global-METER delta across the staging window; exact when the
         # reconciler is quiescent during the stall (pipeline_durability, the
         # default) — concurrent async persist traffic lands here otherwise
@@ -259,13 +283,17 @@ class TCEngine:
     def save(self, step: int, state, *, meta: Optional[dict] = None,
              wait: bool = False) -> SaveHandle:
         """Checkpoint `state` (pytree or flat dict). Blocks only for the
-        in-memory cache write; persistence + backup happen asynchronously."""
+        in-memory cache write; persistence + backup happen asynchronously.
+        The caches adopt the host buffers of the leaves the device-to-host
+        transfer made (:func:`_fresh_host_copies`) and copy every other
+        leaf, so a caller's arrays may change once this returns."""
         if isinstance(state, dict) and all(
                 isinstance(v, np.ndarray) for v in state.values()):
-            flat = state
+            flat, owned = state, frozenset()
         else:
             with obs.span("transom.save.d2h"):
                 flat = flatten_pytree(state)
+                owned = _fresh_host_copies(state)
             obs.count("tce.save.d2h_bytes",
                       sum(a.nbytes for a in flat.values()))
         handle = SaveHandle(step, self)
@@ -279,7 +307,8 @@ class TCEngine:
 
         def _put(rank: int) -> PutStats:
             return self.caches[rank].put(step, per_node[rank],
-                                         n_threads=self.cfg.copy_threads)
+                                         n_threads=self.cfg.copy_threads,
+                                         adopt=owned)
 
         with obs.span("transom.save.cache_write"):
             per_node = shard_state(flat, self.cfg.n_nodes)
@@ -288,6 +317,9 @@ class TCEngine:
         handle.nbytes = sum(p.nbytes for p in puts)
         handle.bytes_staged = sum(p.bytes_staged for p in puts)
         handle.bytes_copied = METER.read() - meter0
+        obs.count("tce.save.copied_bytes", handle.bytes_staged)
+        obs.count("tce.save.adopted_bytes",
+                  handle.nbytes - handle.bytes_staged)
         # nodes write their caches in parallel -> modelled latency is the max
         handle.modeled_cache_s = max(p.bytes_staged for p in puts) \
             / self.cfg.mem_bw

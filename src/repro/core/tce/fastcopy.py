@@ -4,7 +4,9 @@ The paper pipelines ``cudaMemcpy`` D2H through small pinned buffers across n
 threads, chunk size k, because a single-threaded bulk memcpy is CPU-cache-miss
 bound. On a TPU host the D2H DMA is issued by the runtime (``jax.device_get``)
 but the *second* hop — host staging buffer into the cache arena — has exactly
-the same bottleneck, so the chunked multi-threaded structure transfers.
+the same bottleneck, so the chunked multi-threaded structure transfers. (The
+cache adopts the save's own device-to-host buffers and skips that hop; it
+remains for state that is already on the host.)
 
 Two copy modes:
 

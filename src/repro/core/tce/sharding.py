@@ -10,8 +10,10 @@ extension, see DESIGN.md §7).
 
 Zero-copy contract: ``shard_state`` never materialises shard bytes — every
 shard is a *view* into the caller's leaf (axis-0 slices of C-contiguous
-arrays stay contiguous). The single physical copy in the save path happens
-when ``CacheServer.put`` moves these views straight into arena slabs.
+arrays stay contiguous). ``CacheServer.put`` either adopts these views as
+arena slabs (the leaf is the engine's own device-to-host buffer: no copy
+at all) or makes the save path's single physical copy, moving them
+straight into fresh slabs.
 """
 from __future__ import annotations
 

@@ -2,6 +2,7 @@
 compressed persistence (zlib lossless / int8 Pallas quantisation), copy-meter
 accounting, and the legacy-vs-new A/B contract fig8_tce benchmarks."""
 import threading
+import weakref
 import zlib
 
 import numpy as np
@@ -9,8 +10,8 @@ import pytest
 
 from repro.core.tce import (DiskStore, EvictionConfig, METER, TCEConfig,
                             TCEngine, crc32_stream, decode_shard, encode_shard,
-                            shard_state)
-from repro.core.tce.arena import Arena
+                            shard_state, unshard_state)
+from repro.core.tce.arena import Arena, ArenaError
 from repro.core.tce.cache import CacheServer
 
 
@@ -338,4 +339,149 @@ def test_reconciler_single_get_per_entry_pass(tmp_path):
         CacheServer.get = orig
     own_gets = [c for c in calls if c[2] is None]
     assert len(own_gets) == 2          # one per rank, feeding persist AND backup
+    eng.close()
+
+
+# --------------------------------------------------------------------------- #
+# adoption: the cache keeps the save's own device-to-host buffers
+# --------------------------------------------------------------------------- #
+def _jax_state(seed=0):
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    return {"w": jnp.asarray(rng.standard_normal((64, 8)), jnp.float32),
+            "b": jnp.asarray(rng.standard_normal(5), jnp.float16),
+            "step": jnp.asarray(7, jnp.int32)}
+
+
+def _cached(eng, step):
+    return unshard_state([c.get(step) for c in eng.caches])
+
+
+def _off_cpu(monkeypatch):
+    """Report every jax.Array as held off the CPU platform."""
+    from repro.core.tce import engine
+    monkeypatch.setattr(engine, "_platform", lambda arr: "tpu")
+
+
+def test_put_adopt_copies_nothing():
+    cache = CacheServer(0)
+    w = np.arange(4096, dtype=np.float32).reshape(64, 64)
+    shards = shard_state({"w": w, "step": np.array(3, np.int64)}, 1)[0]
+    m0 = METER.read()
+    st = cache.put(10, shards, adopt=set(shards))
+    assert METER.read() == m0
+    assert (st.nbytes, st.bytes_staged) == (w.nbytes + 8, 0)
+    got = cache.get(10)
+    assert np.shares_memory(got["w"][1], w)
+    np.testing.assert_array_equal(got["w"][1], w)
+    assert int(got["step"][1].reshape(())) == 3
+
+
+def test_arena_used_exact_through_adopt_retain_evict_free():
+    a = Arena(3 * 4096)
+    buf = np.zeros(5000, np.uint8)                  # charged as two pages
+    held = weakref.ref(buf)
+    sid = a.adopt(buf)
+    assert a.used == 2 * 4096 and np.shares_memory(a.view(sid), buf)
+    a.retain(sid)
+    a.free_slab(sid)
+    assert a.used == 2 * 4096 and a.refcount(sid) == 1
+    with pytest.raises(ArenaError):                 # one page left
+        a.adopt(np.zeros(4097, np.uint8))
+    assert a.used == 2 * 4096
+    with pytest.raises(ValueError):
+        a.adopt(np.zeros(8, np.float32))
+    del buf
+    assert held() is not None                       # the slab holds it
+    a.free_slab(sid)
+    assert a.used == 0 and held() is None           # last reference gone
+
+    cache = CacheServer(0, EvictionConfig(mem_limit_bytes=2 * 4096,
+                                          max_cycles=100))
+    for step in (10, 20):
+        shards = shard_state({"w": np.zeros(5000, np.uint8)}, 1)[0]
+        cache.put(step, shards, adopt={"w"})
+        assert cache.arena.used == 2 * 4096
+    assert cache.steps() == [20] and cache.evictions == 1
+    cache.wipe()
+    assert cache.arena.used == 0
+
+
+@pytest.mark.parametrize("case", ["non_contiguous", "legacy"])
+def test_put_adopt_falls_back_to_the_copy(case):
+    from repro.core.tce.sharding import ShardSpec
+    w = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)
+    if case == "non_contiguous":
+        cache, data = CacheServer(0), w[:, ::2]
+    else:
+        cache, data = CacheServer(0, legacy=True), w
+    spec = ShardSpec("w", data.shape, "float32", -1, 0, 0)
+    m0 = METER.read()
+    st = cache.put(10, {"w": (spec, data)}, adopt={"w"})
+    assert st.bytes_staged == data.nbytes
+    assert METER.read() - m0 >= data.nbytes
+    got = cache.get(10)["w"][1]
+    assert not np.shares_memory(got, w)
+    np.testing.assert_array_equal(got, data)
+
+
+def test_adopted_save_restores_bit_exact_from_cache_and_store(
+        tmp_path, monkeypatch):
+    _off_cpu(monkeypatch)
+    state = _jax_state(1)
+    want = {k: np.asarray(v).copy() for k, v in state.items()}
+    cfg = TCEConfig(n_nodes=2, backup=False)
+    eng = TCEngine(cfg, DiskStore(str(tmp_path)))
+    h = eng.save(10, state, wait=True)
+    assert h.bytes_staged == 0 and h.nbytes == sum(
+        a.nbytes for a in want.values())
+    for source, restore in [("cache", eng.restore),
+                            ("store", TCEngine(cfg, DiskStore(str(tmp_path)))
+                             .restore)]:
+        step, got = restore()
+        assert step == 10 and set(got) == set(want), source
+        for k in want:
+            assert got[k].dtype == want[k].dtype, (source, k)
+            assert got[k].tobytes() == want[k].tobytes(), (source, k)
+    eng.close()
+
+
+@pytest.mark.parametrize("leaf,off_cpu,adopted", [
+    ("jax", False, False),          # CPU backend: np.asarray is a view
+    ("jax", True, True),            # a device-to-host transfer's buffer
+    ("numpy_dict", True, False),    # the caller's arrays
+    ("numpy_in_tree", True, False),
+])
+def test_save_adopts_only_its_own_device_to_host_buffers(
+        tmp_path, monkeypatch, leaf, off_cpu, adopted):
+    if off_cpu:
+        _off_cpu(monkeypatch)
+    state = _jax_state(2)
+    if leaf == "numpy_dict":
+        state = {k: np.asarray(v).copy() for k, v in state.items()}
+    elif leaf == "numpy_in_tree":
+        state = [np.asarray(v).copy() for v in state.values()]
+    eng = TCEngine(TCEConfig(n_nodes=2, backup=False, async_persist=False),
+                   DiskStore(str(tmp_path)))
+    h = eng.save(10, state)
+    assert h.bytes_staged == (0 if adopted else h.nbytes)
+    w = state["w"] if isinstance(state, dict) else state[0]
+    rows = [c.get(10)["0" if leaf == "numpy_in_tree" else "w"][1]
+            for c in eng.caches]
+    assert all(np.shares_memory(r, np.asarray(w)) == adopted for r in rows)
+    eng.close()
+
+
+def test_cpu_save_leaves_the_next_step_free_to_donate(tmp_path):
+    import jax
+    state = _jax_state(3)
+    want = {k: np.asarray(v).copy() for k, v in state.items()}
+    eng = TCEngine(TCEConfig(n_nodes=2, backup=False), DiskStore(str(tmp_path)))
+    eng.save(10, state, wait=True)
+    step = jax.jit(lambda s: jax.tree.map(lambda a: a * 2 + 1, s),
+                   donate_argnums=0)
+    jax.block_until_ready(step(state))
+    assert all(v.is_deleted() for v in state.values())     # donated
+    got = _cached(eng, 10)
+    assert all(got[k].tobytes() == want[k].tobytes() for k in want)
     eng.close()
