@@ -5,8 +5,11 @@ process (the benchmark's own runs never run this).
     python3 benchmarks/chip/calibrate.py --config olmo1b-6l \
         --seeds 11,12,... --control-seeds 11,12,13 --out calib.json
 
-For each of ``--seeds``: the program's first steps, driven as a run drives
-them, against the float32 reference: the three numbers of
+The configuration's ``layout`` and ``reference`` keys apply as in a run
+(``harness``): the program is planned over the layout's chips, and the
+configuration's reference module runs with its options over them. For each
+of ``--seeds``: the program's first steps, driven as a run drives them,
+against the float32 reference: the three numbers of
 ``harness.compare`` (the lower readings). For each of ``--control-seeds``,
 the same numbers for the controls and a planted fault, each against the
 float32 reference of that seed: the reference with its state and products
@@ -41,7 +44,7 @@ def seeds_arg(s: str):
 
 
 def training_readings(config: dict, traffic: dict, seeds, control_seeds,
-                      log=print) -> dict:
+                      log=print, devices=None) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -49,16 +52,19 @@ def training_readings(config: dict, traffic: dict, seeds, control_seeds,
     from chip.tokens import TokenStream
     from repro.launch import train as lt
 
+    devices = devices or jax.devices()[:1]
     cfg, opt_cfg = harness.build_model(config)
     batch, seq, n = config["batch"], config["seq"], traffic["check_steps"]
-    plan = lt.plan_steps(cfg, opt_cfg, batch, seq)
+    plan = harness.plan_of(lt, config, cfg, opt_cfg, devices)
+    ref, options, _ = harness.reference_of(config)
     sq_norms = jax.jit(reference.slice_sq_norms)
     out = {"program": {}, "controls": {k: {} for k in CONTROLS}}
     refs = {}
 
     def ref_of(seed, data, **kw):
-        return reference.train(config["model"], config["optimizer"], seed,
-                               data.rows(range(n)), **kw)
+        return ref.train(config["model"], config["optimizer"], seed,
+                         data.rows(range(n)), devices=devices, **options,
+                         **kw)
 
     for seed in seeds:
         t0 = time.perf_counter()
@@ -122,18 +128,20 @@ def main(argv=None) -> int:
     config = json.loads((harness.ROOT / conf["file"]).read_text())
     traffic = json.loads((HERE / "traffic" / f"{args.traffic}.json")
                          .read_text())
-    devices = harness.devices_for(1)
+    devices = harness.devices_for(harness.layout_chips(config))
     from repro.launch.compile_cache import setup_compile_cache
     setup_compile_cache()
 
     def log(msg):
         print(msg, file=sys.stderr, flush=True)
 
-    result = {"config": args.config, "device": devices[0].device_kind}
+    result = {"config": args.config, "device": devices[0].device_kind,
+              "chips": len(devices), "layout": config.get("layout")}
     with contextlib.redirect_stdout(sys.stderr):
         if args.seeds or args.control_seeds:
             result["training"] = training_readings(
-                config, traffic, args.seeds, args.control_seeds, log)
+                config, traffic, args.seeds, args.control_seeds, log,
+                devices)
         if args.resume_control_seeds:
             result["resume_int8"] = resume_control(
                 args.resume_cell, args.resume_control_seeds, log)

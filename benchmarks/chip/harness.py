@@ -3,7 +3,20 @@
 A cell is ``<config>.<mix>``. Its configuration is a file of sizes under
 ``configs/``, its traffic a file of parameters under ``traffic/``, each of
 its metrics a reader under ``metrics/``; this module knows none of them by
-name. A traffic file selects the job's phases:
+name. Two optional keys of a configuration say how it runs and is checked:
+
+  layout     mesh axes and sizes over the cell's chips, e.g. {"data": 2,
+             "model": 2}: the step is planned over that mesh under the
+             program's default sharding rules; without it, on one device
+  reference  {"module": the module of this package that holds the
+             configuration's plain reference (default "reference"),
+             "flops": the module that counts its FLOPs (default "flops"),
+             "options": keyword options of the reference's ``train``}
+
+A reference module's ``train(model, opt, seed, batches, *, devices,
+state_dtype, compute_dtype, half_batch, **options)`` returns what
+``compare`` takes, with leaves named as ``reference.slice_sq_norms`` names
+them. A traffic file selects the job's phases:
 
   check_steps  the first steps, compared afterwards with the reference
   save_every   a TCE save after every that many steps (0: none)
@@ -102,6 +115,42 @@ def devices_for(chips: int, require_tpu: bool = True):
     if len(devs) < chips:
         raise NoChip(f"the cell asks for {chips} chips, JAX has {len(devs)}")
     return devs[:chips]
+
+
+def layout_mesh(config: dict, devices):
+    """The mesh the configuration's ``layout`` asks for over ``devices``,
+    or None where it has no layout."""
+    layout = config.get("layout")
+    if not layout:
+        return None
+    if math.prod(layout.values()) != len(devices):
+        raise ValueError(f"layout {layout} does not cover {len(devices)} "
+                         f"chips")
+    from repro.launch.mesh import make_mesh
+    return make_mesh(tuple(layout.values()), tuple(layout), devices=devices)
+
+
+def layout_chips(config: dict) -> int:
+    return math.prod((config.get("layout") or {}).values())
+
+
+def plan_of(lt, config: dict, cfg, opt_cfg, devices):
+    """``plan_steps`` of the configuration: over its layout's mesh, or, with
+    no layout, called as a one-device plan."""
+    mesh = layout_mesh(config, devices)
+    batch, seq = config["batch"], config["seq"]
+    if mesh is None:
+        return lt.plan_steps(cfg, opt_cfg, batch, seq)
+    return lt.plan_steps(cfg, opt_cfg, batch, seq, mesh=mesh)
+
+
+def reference_of(config: dict):
+    """(the configuration's reference module, its ``train`` options, its
+    FLOP count ``train_step_flops``)."""
+    ref = config.get("reference", {})
+    mod = importlib.import_module("chip." + ref.get("module", "reference"))
+    flops = importlib.import_module("chip." + ref.get("flops", "flops"))
+    return mod, ref.get("options", {}), flops.train_step_flops
 
 
 def peak_flops(kind: str, here: Path = HERE) -> float:
@@ -352,7 +401,6 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     from repro.launch.compile_cache import setup_compile_cache
 
     from chip import reference, tracing
-    from chip.flops import train_step_flops
     from chip.tokens import TokenStream
 
     setup_compile_cache()
@@ -366,13 +414,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         phase_t0 = now
 
     conf, traf = cell.config, cell.traffic
+    ref, ref_options, train_step_flops = reference_of(conf)
     cfg, opt_cfg = build_model(conf)
     batch, seq = conf["batch"], conf["seq"]
     check_steps = traf.get("check_steps", 0)
     every = traf.get("save_every", 0)
     resume = traf.get("resume", False)
 
-    plan = lt.plan_steps(cfg, opt_cfg, batch, seq)
+    plan = plan_of(lt, conf, cfg, opt_cfg, devices)
     data = TokenStream.from_traffic(traf, cfg.vocab_size, seq, batch, seed)
     state = plan.init(jax.random.key(seed))
     fp = jax.jit(fingerprint).lower(state).compile()
@@ -487,9 +536,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         free(state)
         del state
         if check_steps:
-            ref = reference.train(conf["model"], conf["optimizer"], seed,
-                                  data.rows(range(check_steps)))
-            nums = compare(program_numbers(readings), ref)
+            nums = compare(program_numbers(readings), ref.train(
+                conf["model"], conf["optimizer"], seed,
+                data.rows(range(check_steps)), devices=devices,
+                **ref_options))
             for k, v in nums.items():
                 checks[k] = (v, conf["limits"][k])
             phase("reference")
@@ -497,7 +547,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         # -- metrics ------------------------------------------------------
         kind = devices[0].device_kind
         run_rec = {
-            "cell": cell.name, "chips": len(devices), "seconds": seconds,
+            "cell": cell.name, "chips": len(devices),
+            "layout": conf.get("layout"), "seconds": seconds,
             "window_s": window_s, "setup_s": setup_s,
             "steps": [{"step": s, "loss": l, "dt": d} for s, l, d in records],
             "tokens_per_step": batch * seq, "input_s": list(data.calls),
@@ -519,7 +570,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         correct = failed == 0 and all(v <= lim for v, lim in checks.values())
         device = {"platform": devices[0].platform, "kind": kind,
                   "count": len(jax.devices()), "memory_peak_bytes": peak,
-                  "step_footprint_bytes": footprint}
+                  "step_footprint_bytes": footprint,
+                  "layout": conf.get("layout")}
         out = {"correct": correct, "attempted": len(losses),
                "failed": failed, "metrics": metrics, "device": device}
         if tr is not None:

@@ -11,7 +11,10 @@ path, scaled by 1/sqrt(fan_in); the embedding by 0.02), so it starts where
 the program starts without taking the program's arrays.
 
 Memory: attention runs in blocks of queries, the loss in blocks of
-positions, each layer under ``jax.checkpoint``.
+positions, each layer under ``jax.checkpoint``. Given the cell's devices,
+``train`` places its own params, moments and gradients over them by a plain
+rule of its own (``placement``), and for a batch that does not fit at once
+it sums the loss and gradient over blocks of rows.
 
 ``state_dtype``/``compute_dtype`` make the controls: the same steps with the
 state kept in a lower precision, or with the products' inputs rounded to
@@ -21,12 +24,14 @@ the rows only.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from types import SimpleNamespace
 from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 HIGHEST = lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -144,6 +149,13 @@ def _attention(q, k, v, mm):
 
 def loss(params, tokens, labels, dims: Dims, cdt=jnp.float32):
     """Mean next-token cross-entropy over every position of the batch."""
+    b, s = tokens.shape
+    return nll_sum(params, tokens, labels, dims, cdt) / (b * s)
+
+
+def nll_sum(params, tokens, labels, dims: Dims, cdt=jnp.float32, keep=None):
+    """Next-token cross-entropy summed over every position of the rows, each
+    row's weighted by ``keep`` (one weight a row) where given."""
     mm = _mm(cdt)
     b, s = tokens.shape
     table = params["tok"]["table"].astype(jnp.float32)
@@ -172,9 +184,10 @@ def loss(params, tokens, labels, dims: Dims, cdt=jnp.float32):
         yi = lax.dynamic_slice_in_dim(labels, i * lb, lb, axis=1)
         logits = mm("bsd,vd->bsv", xi, table)
         picked = jnp.take_along_axis(logits, yi[..., None], -1)[..., 0]
-        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+        nll = jax.nn.logsumexp(logits, axis=-1) - picked
+        return jnp.sum(nll if keep is None else nll * keep[:, None])
 
-    return jnp.sum(lax.map(block_nll, jnp.arange(s // lb))) / (b * s)
+    return jnp.sum(lax.map(block_nll, jnp.arange(s // lb)))
 
 
 # --------------------------------------------------------------------------- #
@@ -236,17 +249,133 @@ def slice_sq_norms(tree) -> Dict[str, jax.Array]:
     return out
 
 
+def placement(shape, mesh: Mesh) -> NamedSharding:
+    """The reference's own rule for where a leaf lives: split along its
+    largest axis that the device count divides (the first of equals), else
+    whole on every device."""
+    n = mesh.devices.size
+    axes = [i for i, k in enumerate(shape) if k % n == 0]
+    if not axes:
+        return NamedSharding(mesh, P())
+    split = max(axes, key=lambda i: (shape[i], -i))
+    return NamedSharding(mesh, P(*("r" if i == split else None
+                                   for i in range(len(shape)))))
+
+
 def train(model: dict, opt: dict, seed: int, batches: Sequence[dict], *,
+          devices: Sequence = (), row_block: int = 0,
           state_dtype=jnp.float32, compute_dtype=jnp.float32,
           half_batch: bool = False) -> dict:
-    """Run ``len(batches)`` steps from the seed's weights on the default
-    device. Returns the loss of each step, the per-slice norms of the first
-    clipped gradient, and of the weights' change over all the steps."""
+    """Run ``len(batches)`` steps from the seed's weights. Returns the loss
+    of each step, the per-slice norms of the first clipped gradient, and of
+    the weights' change over all the steps.
+
+    With more than one of ``devices``, params, moments and gradients are
+    placed over them by ``placement``, and each block's rows are split over
+    them: a block that the device count does not divide is padded with rows
+    of weight 0. ``row_block``: loss and gradient are summed in float32
+    over blocks of that many rows, and divided once by the number of
+    positions. With one device and no ``row_block`` the whole batch runs at
+    once on the default device."""
     dims = Dims.of(model)
     rows = len(batches[0]["tokens"])
     if half_batch:      # the first half of the rows
         rows = rows // 2
+    spread = len(devices) > 1
+    if not (spread or row_block):
+        return _train_whole(dims, opt, seed, batches, rows, state_dtype,
+                            compute_dtype)
 
+    f = programs(dims, opt, seed, devices if spread else None, state_dtype,
+                 compute_dtype)
+    block = row_block or rows
+    n_dev = len(devices) if spread else 1
+    params = f.init()
+    m, v = f.moments(params), f.moments(params)
+    losses: List[float] = []
+    grad_sq = None
+    for i, bt in enumerate(batches):
+        g, total = f.zeros()
+        for lo in range(0, rows, block):
+            hi = min(lo + block, rows)
+            pad = -(hi - lo) % n_dev
+            t, y = (jax.device_put(np.pad(np.asarray(bt[k][lo:hi]),
+                                          ((0, pad), (0, 0))), f.rows)
+                    for k in ("tokens", "labels"))
+            keep = None if not pad else jax.device_put(
+                np.repeat(np.float32([1, 0]), [hi - lo, pad]), f.rows)
+            g, total = f.accumulate(params, g, total, t, y, keep)
+        g, total = f.mean(g, total, np.float32(rows * len(bt["tokens"][0])))
+        losses.append(float(total))
+        params, m, v, g = f.step(params, g, m, v, np.float32(lr_at(opt, i)),
+                                 np.float32(i + 1))
+        if grad_sq is None:
+            grad_sq = {k: float(x) for k, x in f.sq(g).items()}
+        del g
+    del m, v
+    ch = {k: float(x) for k, x in f.change(params, f.init()).items()}
+    return _result(losses, grad_sq, ch)
+
+
+def programs(dims: Dims, opt: dict, seed: int, devices, state_dtype=jnp.float32,
+             compute_dtype=jnp.float32) -> SimpleNamespace:
+    """The jitted parts of a run in blocks of rows: over ``devices`` by
+    ``placement`` where given, else on the default device. ``rows`` is
+    where a block's rows go: split over the devices."""
+    shapes = jax.eval_shape(lambda: init_params(dims, seed))
+    if devices:
+        mesh = Mesh(np.array(list(devices)), ("r",),
+                    axis_types=(AxisType.Auto,))
+        psh = jax.tree.map(lambda x: placement(x.shape, mesh), shapes)
+        one = NamedSharding(mesh, P())
+        split = NamedSharding(mesh, P("r"))
+        kw = lambda out: {"out_shardings": out}                # noqa: E731
+    else:
+        psh = one = split = None
+        kw = lambda out: {}                                    # noqa: E731
+
+    def add_block(p, g, total, t, y, keep):
+        val, grad = jax.value_and_grad(
+            lambda q: nll_sum(q, t, y, dims, compute_dtype, keep))(p)
+        return jax.tree.map(lambda a, b: a + b.astype(jnp.float32), g,
+                            grad), total + val
+
+    return SimpleNamespace(
+        rows=split,
+        init=jax.jit(lambda: jax.tree.map(lambda a: a.astype(state_dtype),
+                                          init_params(dims, seed)),
+                     **kw(psh)),
+        zeros=jax.jit(lambda: (jax.tree.map(
+            lambda x: jnp.zeros(x.shape, jnp.float32), shapes),
+            jnp.zeros((), jnp.float32)), **kw((psh, one))),
+        moments=jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                        **kw(psh)),
+        accumulate=jax.jit(add_block, donate_argnums=(1, 2),
+                           **kw((psh, one))),
+        mean=jax.jit(lambda g, total, n: (jax.tree.map(lambda a: a / n, g),
+                                          total / n), **kw((psh, one))),
+        step=jax.jit(lambda p, g, m, v, lr, t: adam(p, g, m, v, lr, t, opt),
+                     donate_argnums=(0, 2, 3), **kw((psh,) * 4)),
+        sq=jax.jit(slice_sq_norms),
+        change=jax.jit(_change_sq_norms))
+
+
+def _change_sq_norms(after, before):
+    return slice_sq_norms(jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32),
+        after, before))
+
+
+def _result(losses, grad_sq, change_sq) -> dict:
+    return {"losses": losses,
+            "grad_norms": {k: float(np.sqrt(x)) for k, x in grad_sq.items()},
+            "change_norms": {k: float(np.sqrt(x))
+                             for k, x in change_sq.items()}}
+
+
+def _train_whole(dims: Dims, opt: dict, seed: int, batches, rows: int,
+                 state_dtype, compute_dtype) -> dict:
+    """The whole batch at once on the default device."""
     init = jax.jit(lambda: jax.tree.map(lambda a: a.astype(state_dtype),
                                         init_params(dims, seed)))
     grad_fn = jax.jit(jax.value_and_grad(
@@ -275,6 +404,4 @@ def train(model: dict, opt: dict, seed: int, batches: Sequence[dict], *,
     del m, v
     p0 = init()
     ch = {k: float(x) for k, x in change(params, p0).items()}
-    return {"losses": losses,
-            "grad_norms": {k: float(np.sqrt(x)) for k, x in grad_sq.items()},
-            "change_norms": {k: float(np.sqrt(x)) for k, x in ch.items()}}
+    return _result(losses, grad_sq, ch)

@@ -82,4 +82,16 @@ def test_command_exits_nonzero_with_only_the_benchmark(tmp_path):
 def test_tiny_copies_resolve():
     bench = tiny.tiny_bench()
     for w in bench["workloads"]:
-        harness.resolve(w["name"], bench)
+        c = harness.resolve(w["name"], bench)
+        assert harness.layout_chips(c.config) == c.chips
+
+
+def test_tiny_copies_of_one_chip_cells_run_on_one_device():
+    # the four-chip cell's tiny copy runs in a process with four devices
+    # (test_chip_bench_x4.py); in this one it finds too few
+    bench = tiny.tiny_bench()
+    chips = {w["name"]: w["chips"] for w in bench["workloads"]}
+    assert chips["tiny.train"] == 1 and chips["tiny-x4.train"] == 4
+    assert tiny.run_tiny("tiny.train")["device"]["count"] == 1
+    with pytest.raises(harness.NoChip):
+        tiny.run_tiny("tiny-x4.train")

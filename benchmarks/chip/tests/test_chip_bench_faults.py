@@ -1,9 +1,6 @@
 """A run with the timed path broken underneath comes out not correct: the
 harness's look for a chip is skipped, everything else of a run is driven
 at a toy size on the CPU."""
-import dataclasses
-
-import jax
 import numpy as np
 import pytest
 
@@ -11,28 +8,8 @@ import chip_bench_tiny as tiny
 
 
 def broken_plans(monkeypatch, fault):
-    """Replace the step that ``plan_steps`` returns with a faulty one."""
     from repro.launch import train as lt
-    from repro.train import TrainConfig, make_train_step
-    real = lt.plan_steps
-
-    def plan_steps(cfg, opt_cfg, batch, seq):
-        plan = real(cfg, opt_cfg, batch, seq)
-        core = make_train_step(cfg, opt_cfg, TrainConfig())
-        if fault == "state_unchanged":
-            step = jax.jit(lambda s, b: (s, core(s, b)[1]))
-        elif fault == "half_batch":
-            step = jax.jit(lambda s, b: core(
-                s, {k: v[: v.shape[0] // 2] for k, v in b.items()}),
-                donate_argnums=(0,))
-        else:                                    # the loss altered
-            def altered(s, b):
-                s, m = core(s, b)
-                return s, dict(m, loss=m["loss"] * 1.001)
-            step = jax.jit(altered, donate_argnums=(0,))
-        return dataclasses.replace(plan, step=step)
-
-    monkeypatch.setattr(lt, "plan_steps", plan_steps)
+    monkeypatch.setattr(lt, "plan_steps", tiny.faulty_plan_steps(fault))
 
 
 @pytest.mark.parametrize("cell", ["tiny.train", "tiny.resume"])
@@ -41,6 +18,26 @@ def test_sound_run_is_correct(cell):
     assert out["correct"], out["checks"]
     # the compiled step needs at least the state it is given
     assert out["device"]["step_footprint_bytes"] > 0
+
+
+# the checks of the one-chip cells' tiny copies at one seed, as they read
+# before configurations could name a layout and a reference
+BEFORE = {"loss_gap": 6.040038841831574e-05,
+          "grad_norm_gap": 0.0015033101077957657,
+          "update_norm_gap": 0.0004163619506500744,
+          "ckpt_leaves_differ": 0, "resume_loss_gap": 0.0,
+          "resume_leaves_differ": 0}
+
+
+@pytest.mark.parametrize("cell", ["tiny.train", "tiny.train_ckpt",
+                                  "tiny.resume"])
+def test_sound_run_reads_as_before(cell):
+    # a save every 2 steps, so that the short window holds one on any CPU
+    saves = {"save_every": 2} if cell == "tiny.train_ckpt" else {}
+    out = tiny.run_tiny(cell, seed=2**31 + 1515, **saves)
+    got = {k: c["value"] for k, c in out["checks"].items()}
+    assert got == {k: BEFORE[k] for k in got}
+    assert {"loss_gap", "grad_norm_gap", "update_norm_gap"} <= set(got)
 
 
 @pytest.mark.parametrize("cell", ["tiny.train", "tiny.resume"])

@@ -64,3 +64,35 @@ def test_fingerprint_sees_one_changed_word():
     tree["f"][1, 2, 3] += 1.0
     assert harness.leaves_differ(before, fp(tree)) == 1
     assert harness.leaves_differ(before, fp(swapped)) == 1
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["no_devices",
+                                                    "one_device"])
+def test_one_block_of_all_rows_on_one_device_is_the_plain_reference(
+        tiny_config, given):
+    from chip.tokens import TokenStream
+    data = TokenStream(tiny_config["model"]["vocab_size"], tiny_config["seq"],
+                       tiny_config["batch"], SEED, zipf_a=1.3, n_patterns=64,
+                       noise=0.15)
+    args = (tiny_config["model"], tiny_config["optimizer"], SEED,
+            data.rows(range(3)))
+    blocked = reference.train(*args, devices=jax.devices()[:given],
+                              row_block=tiny_config["batch"])
+    # 4 x 32 positions: dividing once at the end is exact, so bit for bit
+    assert blocked == reference.train(*args)
+
+
+def test_a_configuration_names_its_reference_module(tiny_config):
+    from chip.tests import ref_probe
+    config = dict(tiny_config, reference={"module": "tests.ref_probe",
+                                          "flops": "tests.ref_probe"})
+    mod, options, count = harness.reference_of(config)
+    assert mod is ref_probe and options == {}
+    assert harness.reference_of(tiny_config)[0] is reference
+    cell = harness.resolve("tiny.train", tiny.tiny_bench())
+    cell.config = config
+    ref_probe.CALLS.clear()
+    out = harness.run(cell, SEED, 0.2, False, 0.0, require_tpu=False)
+    assert out["correct"], out["checks"]
+    assert [c[0] for c in ref_probe.CALLS] == ["train", "flops"]
+    assert ref_probe.CALLS[0][1]["devices"] == jax.devices()[:1]

@@ -9,6 +9,7 @@ import pytest
 CHIP = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(CHIP.parent))
 
+from chip import harness  # noqa: E402
 from chip import tracing as T  # noqa: E402
 
 CPU_TRACE = CHIP / "tests" / "cpu_trace.xplane.pb"
@@ -92,3 +93,62 @@ def test_recorded_cpu_trace():
     # the sleeps inside the batch and save spans are idle device time
     assert idle["bench.input"] > 0.003 and idle["bench.save"] > 0.003
     assert 0 < T.busy(tr, T.step_intervals(tr)) <= busy
+
+
+def test_collectives_hidden_and_exposed():
+    # one device, two steps, its ops one after another inside a loop's
+    # event (which holds ops, so does not count as running). Step 1: an
+    # async all-gather, start 1.0 to done 3.0, with a fusion between, then
+    # a TPU async-collective fusion pair, 3.0 to 3.5, with a fusion
+    # between: only their start and done ops (0.4 s) are exposed. Step 2:
+    # a reduce-scatter, start 5.8 to done 7.0, with a fusion 5.9-6.0, a
+    # synchronous all-reduce 6.0-6.5 and an idle gap 6.5-6.6 inside it,
+    # then a synchronous collective-permute 7.0-7.2: 1.3 s exposed, of
+    # which the ops themselves run 1.2 s.
+    tr = T.Trace()
+    tr.devices["/device:TPU:0"] = [
+        ("%while.1 = (s32[]) while(s32[] %p)", 0.2, 7.5),
+        ("%fusion.1 = f32[8] fusion(f32[8] %a)", 0.5, 1.0),
+        ("%all-gather-start.3 = (f32[4], f32[8]) all-gather-start(f32[4] "
+         "%x)", 1.0, 1.1),
+        ("%fusion.3 = f32[8] fusion(f32[8] %b)", 1.1, 2.9),
+        ("%all-gather-done.3 = f32[8] all-gather-done((f32[4], f32[8]) "
+         "%all-gather-start.3)", 2.9, 3.0),
+        ("%async-collective-start.2 = (f32[4], f32[8]) fusion(f32[4] %w), "
+         "kind=kCustom", 3.0, 3.1),
+        ("%fusion.5 = f32[8] fusion(f32[8] %d)", 3.1, 3.4),
+        ("%async-collective-done.2 = f32[8] fusion(f32[4] %get-tuple-"
+         "element.1, f32[8] %get-tuple-element.2), kind=kCustom", 3.4, 3.5),
+        ("%fusion.2 = f32[8] fusion(f32[8] %all-gather-done.3)", 5.5, 5.8),
+        ("%reduce-scatter-start.1 = (f32[8], f32[2]) "
+         "reduce-scatter-start(f32[8] %y)", 5.8, 5.9),
+        ("%fusion.4 = f32[8] fusion(f32[8] %c)", 5.9, 6.0),
+        ("%all-reduce.7 = f32[8] all-reduce(f32[8] %z)", 6.0, 6.5),
+        ("%reduce-scatter-done.1 = f32[2] reduce-scatter-done((f32[8], "
+         "f32[2]) %reduce-scatter-start.1)", 6.6, 7.0),
+        ("%collective-permute.2 = f32[2] collective-permute(f32[2] %r)",
+         7.0, 7.2)]
+    tr.host = [("bench.window", 0.0, 10.0), ("bench.train_span", 0.0, 4.0),
+               ("bench.train_span", 5.0, 8.0)]
+    assert T.collective_intervals(tr.devices["/device:TPU:0"]) == \
+        pytest.approx([(1.0, 3.0), (3.0, 3.5), (6.0, 6.5), (5.8, 7.0),
+                       (7.0, 7.2)])
+    steps = T.step_intervals(tr)
+    assert T.collective_op_seconds(tr, steps) == pytest.approx(1.6)
+    assert T.collective_exposed_seconds(tr, steps) == pytest.approx(1.7)
+    run = {"trace": tr, "steps": [{}, {}]}
+    want = {"collective_ms": 800, "collective_ms.all_reduce": 250,
+            "collective_ms.async": 100, "collective_ms.permute": 100,
+            "collective_exposed_ms": 850}
+    assert {k: harness.load_reader(k)(run) for k in want} == \
+        pytest.approx(want)
+    # a second device with no collective halves the means
+    tr.devices["/device:TPU:1"] = [("%fusion.1 = f32[8] fusion()", 0.5, 3.5)]
+    assert {k: harness.load_reader(k)(run) for k in want} == \
+        pytest.approx({k: v / 2 for k, v in want.items()})
+    # no collective anywhere, or none of a kind: nothing to read
+    one = T.Trace(devices={"d": [("fusion.1", 0.5, 3.5)]}, host=tr.host)
+    for k in want:
+        assert harness.load_reader(k)({"trace": one, "steps": [{}]}) is None
+    del tr.devices["/device:TPU:0"][-1]
+    assert harness.load_reader("collective_ms.permute")(run) is None

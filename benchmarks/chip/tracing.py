@@ -21,10 +21,13 @@ Interval = Tuple[float, float]
 Event = Tuple[str, float, float]         # (name, start_s, end_s)
 
 TPU_PLANE = re.compile(r"^/device:TPU:\d+$")
+# the TPU compiler runs an all-gather or reduce-scatter it overlaps with
+# compute as a custom fusion pair, async-collective-start and -done
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
-               "collective-permute", "all-to-all")
+               "collective-permute", "all-to-all", "async-collective")
 _SUFFIX = re.compile(r"(\.\d+)+$")
 _HLO_NAME = re.compile(r"^%?([^\s=]+)\s*=")
+_START_OPERAND = re.compile(r"%([\w.\-]+-start(?:\.\d+)*)\b")
 
 
 @dataclass
@@ -159,6 +162,89 @@ def op_seconds(tr: Trace, within: Sequence[Interval],
 
 def is_collective(name: str) -> bool:
     return any(c in name for c in COLLECTIVES)
+
+
+def leaf_ops(evs: Sequence[Event]) -> List[Event]:
+    """The ops of one line that hold no other op (a loop's own event holds
+    its body's ops)."""
+    order = sorted(evs, key=lambda x: (x[1], -x[2]))
+    holds = [False] * len(order)
+    stack: List[int] = []
+    for i, (_, s, _) in enumerate(order):
+        while stack and order[stack[-1]][2] <= s:
+            stack.pop()
+        if stack:
+            holds[stack[-1]] = True
+        stack.append(i)
+    return [ev for ev, h in zip(order, holds) if not h]
+
+
+def collective_intervals(evs: Sequence[Event]) -> List[Interval]:
+    """Each collective of one device's op line, from its start to its end.
+    An asynchronous one shows as two ops, ``<collective>-start`` and
+    ``<collective>-done``: it runs from the start op's start to the end of
+    the done op that follows it. A done's HLO text names its start as its
+    operand, except the TPU's ``async-collective-done.N`` fusion, whose
+    start is ``async-collective-start.N``."""
+    out: List[Interval] = []
+    started: Dict[str, float] = {}
+    for name, s, e in sorted(evs, key=lambda x: x[1]):
+        op = op_name(name)
+        if not is_collective(op):
+            continue
+        if op.endswith("-start"):
+            started[_instruction(name)] = s
+        elif op.endswith("-done"):
+            m = _START_OPERAND.search(name.split("=", 1)[-1])
+            key = m.group(1) if m else \
+                _instruction(name).replace("-done", "-start")
+            out.append((started.pop(key, s), e))
+        else:
+            out.append((s, e))
+    return out
+
+
+def collective_op_seconds(tr: Trace, within: Sequence[Interval],
+                          kinds: Sequence[str] = COLLECTIVES
+                          ) -> Optional[float]:
+    """Device time of the collective ops of those ``kinds`` themselves: each
+    synchronous op, and each ``-start`` and ``-done`` op of an asynchronous
+    one, not the time between them. Inside ``within``, mean over devices;
+    None where no device ran such an op there."""
+    within = union(within)
+    tot = 0.0
+    found = False
+    for evs in tr.devices.values():
+        own = intersect(union((s, e) for name, s, e in evs
+                              if any(k in op_name(name) for k in kinds)),
+                        within)
+        found = found or bool(own)
+        tot += length(own)
+    return tot / len(tr.devices) if found else None
+
+
+def collective_exposed_seconds(tr: Trace, within: Sequence[Interval]
+                               ) -> Optional[float]:
+    """Device time in which a collective was in flight (from its start to
+    its end, ``collective_intervals``) and no other op ran on the same
+    device, inside ``within``, mean over devices; None where no device ran
+    a collective there."""
+    within = union(within)
+    exposed = 0.0
+    found = False
+    for evs in tr.devices.values():
+        coll = intersect(union(collective_intervals(evs)), within)
+        found = found or bool(coll)
+        other = union((s, e) for name, s, e in leaf_ops(evs)
+                      if not is_collective(op_name(name)))
+        exposed += length(coll) - length(intersect(coll, other))
+    return exposed / len(tr.devices) if found else None
+
+
+def _instruction(name: str) -> str:
+    """An op's instruction name with its number: 'all-gather-start.3'."""
+    m = _HLO_NAME.match(name)
+    return m.group(1) if m else name
 
 
 def op_name(name: str) -> str:
