@@ -13,6 +13,11 @@ name. Two optional keys of a configuration say how it runs and is checked:
              "flops": the module that counts its FLOPs (default "flops"),
              "options": keyword options of the reference's ``train``}
 
+What a configuration may cut from its source is one rule,
+``check_cuts``, checked wherever a cell resolves. A key of ``model`` that
+holds a dict is merged field by field into the registry's block of that
+name (``build_model``).
+
 A reference module's ``train(model, opt, seed, batches, *, devices,
 state_dtype, compute_dtype, half_batch, **options)`` returns what
 ``compare`` takes, with leaves named as ``reference.slice_sq_norms`` names
@@ -93,6 +98,7 @@ def resolve(name: str, bench: Optional[dict] = None, root: Path = ROOT,
     w = work[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((root / conf["file"]).read_text())
+    check_cuts(conf, config)
     traffic = json.loads((here / "traffic" / f"{w['traffic']}.json")
                          .read_text())
 
@@ -102,6 +108,100 @@ def resolve(name: str, bench: Optional[dict] = None, root: Path = ROOT,
 
     return Cell(name, w["chips"], config, traffic,
                 readers(bench["end_to_end"]), readers(bench["per_layer"]))
+
+
+# --------------------------------------------------------------------------- #
+# What a configuration may cut from its source
+# --------------------------------------------------------------------------- #
+WIDTHS = ("d_model", "d_head", "d_ff")
+WIDTH_BLOCKS = ("ssm", "mla", "moe")     # every field a width, but:
+EXPERTS = "moe.n_experts"                # the count of routed experts
+SHARE_KEYS = ("vocab_size", "n_heads", EXPERTS)
+ALSO_NAMED = {"batch_tokens": "batch"}   # published key: its name in reduced
+
+
+def flat(tree: dict, prefix: str = "") -> dict:
+    """Nested blocks' keys as ``block.field``."""
+    out = {}
+    for k, v in tree.items():
+        if isinstance(v, dict):
+            out.update(flat(v, f"{prefix}{k}."))
+        else:
+            out[prefix + k] = v
+    return out
+
+
+def is_width(key: str) -> bool:
+    block, dot, _ = key.partition(".")
+    return key in WIDTHS or (block in WIDTH_BLOCKS and dot == "." and
+                             key != EXPERTS)
+
+
+def check_cuts(conf: dict, config: dict) -> None:
+    """Raises ValueError where the configuration file ``config`` of the
+    BENCHMARK.json entry ``conf`` cuts its source by more than it may.
+
+    The configuration run is ``build_model``'s, nested blocks flattened to
+    ``block.field``, with the file's ``seq`` and ``batch_tokens`` (batch x
+    seq, named ``batch`` in ``reduced``). ``published`` states every width
+    it has (``WIDTHS``, every field of ``ssm``, ``mla`` and ``moe`` but the
+    count of experts), and every key of ``published`` equals the value run
+    unless ``reduced`` lists it or its block. ``reduced`` names no width and
+    no block that holds one, and no width differs, listed or not. A chip's
+    share of a layer (``SHARE_KEYS``; ``n_heads`` with ``n_kv_heads``) may
+    be cut only where ``share`` states it: ``{key: {"chips": c, "how":
+    ...}}``, with the value run x c = the published count exactly (for
+    ``n_heads``, the key-value heads too), at least an eighth of the
+    vocabulary and at least 8 routed experts. A file with no ``published``
+    block is its own source (the test copies): it may cut nothing."""
+    name = conf["name"]
+
+    def fail(msg):
+        raise ValueError(f"configuration {name}: {msg}")
+
+    reduced = config.get("reduced", [])
+    if config.get("name") != name or reduced != conf.get("reduced", []):
+        fail("its file's name and reduced differ from BENCHMARK.json's")
+    run = flat(dict(dataclasses.asdict(build_model(config)[0]),
+                    seq=config["seq"],
+                    batch_tokens=config["batch"] * config["seq"]))
+    published = flat(config["published"]) if "published" in config else run
+    shares = config.get("share", {})
+    for k in reduced:
+        if is_width(k) or any(is_width(w) for w in run
+                              if w.startswith(k + ".")):
+            fail(f"reduced names {k}, which is or holds a width")
+    for k in run:
+        if is_width(k) and k not in published:
+            fail(f"width {k} runs at {run[k]} and published does not "
+                 f"state it")
+    for k, want in published.items():
+        if k not in run:
+            fail(f"published {k} is not in the configuration run")
+        if run[k] == want:
+            continue
+        if is_width(k):
+            fail(f"width {k} is {run[k]}, published {want}")
+        if not {k, k.split(".")[0], ALSO_NAMED.get(k)} & set(reduced):
+            fail(f"{k} is {run[k]}, published {want}, and reduced does "
+                 f"not list it")
+        if k in SHARE_KEYS + ("n_kv_heads",) and \
+                ("n_heads" if k == "n_kv_heads" else k) not in shares:
+            fail(f"{k} is cut with no share entry stating it")
+    for k, s in shares.items():
+        if k not in SHARE_KEYS or k not in reduced or k not in run:
+            fail(f"share {k}: not a share key, not in reduced, or not run")
+        held, chips, total = run[k], s["chips"], published.get(k)
+        if held * chips != total:
+            fail(f"share {k}: {held} held x {chips} chips must equal the "
+                 f"published {total}")
+        if k == "n_heads" and \
+                run["n_kv_heads"] * chips != published.get("n_kv_heads"):
+            fail("share n_heads: the key-value heads are not divided alike")
+        if k == "vocab_size" and held * 8 < total:
+            fail(f"share vocab_size: {held} rows, under an eighth of {total}")
+        if k == EXPERTS and held < 8:
+            fail(f"share {EXPERTS}: {held} experts held, under 8")
 
 
 # --------------------------------------------------------------------------- #
@@ -377,9 +477,25 @@ def step_footprint(plan, state, batch) -> int:
 # One run
 # --------------------------------------------------------------------------- #
 def build_model(config: dict):
+    """(model config, optimizer config): the registry's ``arch`` with
+    ``model``'s keys replaced; a key that holds a dict replaces fields of
+    the registry's block of that name."""
     from repro.configs import get_config
     from repro.train import AdamConfig
-    cfg = dataclasses.replace(get_config(config["arch"]), **config["model"])
+    base = get_config(config["arch"])
+    model = {}
+    for k, v in config["model"].items():
+        if isinstance(v, dict):
+            block = getattr(base, k, None)
+            if not dataclasses.is_dataclass(block):
+                raise ValueError(f"model.{k}: {config['arch']} has no {k} "
+                                 f"block")
+            unknown = set(v) - {f.name for f in dataclasses.fields(block)}
+            if unknown:
+                raise ValueError(f"model.{k}: no fields {sorted(unknown)}")
+            v = dataclasses.replace(block, **v)
+        model[k] = v
+    cfg = dataclasses.replace(base, **model)
     return cfg, AdamConfig(**config["optimizer"])
 
 

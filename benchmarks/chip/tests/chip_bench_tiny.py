@@ -46,6 +46,21 @@ def run_tiny(cell: str, seed: int = 2**31 + 77, seconds: float = 0.5,
                        require_tpu=False)
 
 
+def altered_saves(flatten):
+    """``engine.flatten_pytree`` with one word of the largest leaf of each
+    save altered: a checkpoint altered where the save takes it."""
+    import numpy as np
+
+    def altered(tree):
+        flat = flatten(tree)
+        key = max(flat, key=lambda k: flat[k].size)
+        leaf = np.array(flat[key])
+        leaf.reshape(-1)[0] += 1
+        flat[key] = leaf
+        return flat
+    return altered
+
+
 def faulty_plan_steps(fault: str):
     """A ``plan_steps`` whose step is broken: ``state_unchanged`` (the step
     returns its state as it got it), ``half_batch`` (the loss over the

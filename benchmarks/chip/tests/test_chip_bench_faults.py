@@ -1,7 +1,6 @@
 """A run with the timed path broken underneath comes out not correct: the
 harness's look for a chip is skipped, everything else of a run is driven
 at a toy size on the CPU."""
-import numpy as np
 import pytest
 
 import chip_bench_tiny as tiny
@@ -52,16 +51,7 @@ def test_broken_step_is_not_correct(monkeypatch, fault, cell):
 @pytest.mark.parametrize("cell", ["tiny.train_ckpt", "tiny.resume"])
 def test_altered_checkpoint_is_not_correct(monkeypatch, cell):
     from repro.core.tce import engine
-    real = engine.flatten_pytree
-
-    def altered(tree):
-        flat = real(tree)
-        key = max(flat, key=lambda k: flat[k].size)
-        leaf = np.array(flat[key])
-        leaf.reshape(-1)[0] += 1
-        flat[key] = leaf
-        return flat
-
-    monkeypatch.setattr(engine, "flatten_pytree", altered)
+    monkeypatch.setattr(engine, "flatten_pytree",
+                        tiny.altered_saves(engine.flatten_pytree))
     out = tiny.run_tiny(cell, save_every=20)
     assert not out["correct"], out["checks"]

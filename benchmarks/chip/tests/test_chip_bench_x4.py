@@ -2,9 +2,11 @@
 process of its own (``x4_probe.py``): a whole run of the tiny copy of the
 four-chip cell through ``harness.run`` passes its checks with the state
 split over the devices, and comes out not correct with its step broken
-underneath (the exchange between chips left out among the faults); and the
-reference spread over the devices in blocks of rows gives what the plain
-one gives, to float32 rounding."""
+underneath (the exchange between chips left out among the faults); with
+TCE saves it reads its sharded save back exactly, reads the save and
+persist metrics traced, and comes out not correct where the save is
+altered; and the reference spread over the devices in blocks of rows
+gives what the plain one gives, to float32 rounding."""
 import json
 import os
 import subprocess
@@ -13,6 +15,7 @@ import sys
 import pytest
 
 import chip_bench_tiny as tiny
+from chip import harness
 
 PROBE = tiny.HERE / "x4_probe.py"
 
@@ -48,6 +51,33 @@ def test_run_splits_the_state_over_the_devices(probe):
                                    "loss_altered", "no_exchange"])
 def test_broken_step_on_a_2x2_mesh_is_not_correct(probe, fault):
     assert probe["faults"][fault] is False
+
+
+def test_sharded_save_reads_back_exactly(probe):
+    run = probe["saves"]["sound"]
+    assert run["count"] == 4
+    assert run["correct"], run["checks"]
+    assert run["checks"]["ckpt_leaves_differ"] == {"value": 0, "limit": 0}
+    assert {"loss_gap", "grad_norm_gap", "update_norm_gap"} \
+        <= set(run["checks"])
+
+
+def test_traced_sharded_save_reads_its_per_layer_metrics(probe):
+    # every per-layer metric of the cell that saves, but those that need
+    # the chip: the device trace's (the CPU has no device plane) and mfu (a
+    # chip's peak)
+    cell = "olmo1b-6l.train_ckpt"
+    want = {m["name"] for m in harness.load_benchmark()["per_layer"]
+            if cell in m["workloads"] and m["source"] != "device_trace"}
+    run = probe["saves"]["traced"]
+    assert run["correct"]
+    assert want - {"mfu"} <= set(run["read"])
+
+
+def test_altered_sharded_save_is_not_correct(probe):
+    run = probe["saves"]["altered"]
+    assert not run["correct"]
+    assert run["checks"]["ckpt_leaves_differ"]["value"] > 0
 
 
 @pytest.mark.parametrize("plain,spread", [("plain", "spread"),

@@ -3,9 +3,12 @@
 before JAX starts): a whole run of the tiny copy of the four-chip cell
 through ``harness.run``, with the bytes of the program's state that each
 device holds; the same run with each fault of the step planted
-(``chip_bench_tiny.faulty_plan_steps``); and the reference spread over the
-devices in blocks of rows (the last block padded with rows of weight 0)
-beside the plain one. Prints one JSON object as its last line.
+(``chip_bench_tiny.faulty_plan_steps``); the same copy with TCE saves,
+its sharded state saved and read back, traced, and with the saved state
+altered where the save takes it from the devices; and the reference
+spread over the devices in blocks of rows (the last block padded with
+rows of weight 0) beside the plain one. Prints one JSON object as its last
+line.
 
     python3 benchmarks/chip/tests/x4_probe.py
 """
@@ -19,7 +22,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 import chip_bench_tiny as tiny
-from chip import reference
+from chip import harness, reference
 from chip.tokens import TokenStream
 
 SEED = 2**31 + 404
@@ -72,6 +75,34 @@ def faulty_runs() -> dict:
     return out
 
 
+def saving_runs() -> dict:
+    """The tiny copy of the four-chip cell with a TCE save every 2 steps,
+    so that the short window holds one: sound; traced, with the per-layer
+    metrics of the one-chip cell that saves (the per-layer metrics it
+    read); and with the largest leaf of each save altered by one word as
+    the engine flattens it."""
+    from repro.core.tce import engine
+    sound = tiny.run_tiny("tiny-x4.train", seed=SEED, save_every=2)
+    bench = tiny.tiny_bench()
+    cell = harness.resolve("tiny-x4.train", bench)
+    cell.per_layer = harness.resolve("tiny.train_ckpt", bench).per_layer
+    cell.traffic.update(save_every=2)
+    traced = harness.run(cell, SEED, 0.5, True, time.perf_counter(),
+                         require_tpu=False)
+    real = engine.flatten_pytree
+    engine.flatten_pytree = tiny.altered_saves(real)
+    try:
+        bad = tiny.run_tiny("tiny-x4.train", seed=SEED, save_every=2)
+    finally:
+        engine.flatten_pytree = real
+    out = {k: {"correct": r["correct"], "checks": r["checks"],
+               "count": r["device"]["count"]}
+           for k, r in (("sound", sound), ("altered", bad))}
+    out["traced"] = {"correct": traced["correct"],
+                     "read": sorted(traced["metrics"])}
+    return out
+
+
 def reference_pairs() -> dict:
     config = json.loads((tiny.HERE / "tiny-x4.json").read_text())
     data = TokenStream(config["model"]["vocab_size"], config["seq"],
@@ -95,7 +126,8 @@ def reference_pairs() -> dict:
 def main() -> int:
     t0 = time.perf_counter()
     out = {"devices": len(jax.devices()), "run": harness_run(),
-           "faults": faulty_runs(), "reference": reference_pairs()}
+           "faults": faulty_runs(), "saves": saving_runs(),
+           "reference": reference_pairs()}
     out["seconds"] = time.perf_counter() - t0
     print(json.dumps(out))
     return 0
